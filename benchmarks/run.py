@@ -1263,21 +1263,31 @@ BENCHES = {
 }
 
 
-def main() -> None:
+def main(argv=None) -> int:
+    """Run the selected benches; every bench runs even after one fails,
+    and the exit code is 1 when any of them raised."""
+    from repro.launch.compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--fast", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in BENCHES.items():
         if args.only and args.only != name:
             continue
         try:
             fn(args.fast)
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # record, run the rest, fail at the end
             emit(name, -1.0, f"ERROR:{type(e).__name__}:{e}")
+            failed.append(name)
+    if failed:
+        print(f"FAILED: {','.join(failed)}")
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -13,7 +13,9 @@ is the single source of that decision:
   the Pallas interpreter).
 
 Wrappers resolve ``interpret=None`` through this helper at trace time, so
-an ``interpret`` kwarg stays available for tests that pin one mode.
+an ``interpret`` kwarg stays available for tests that pin one mode.  The
+serving engine calls :func:`require_compiled_kernels`: on a TPU an
+interpreted kernel is a misconfiguration, never a fallback.
 """
 from __future__ import annotations
 
@@ -37,3 +39,12 @@ def default_interpret() -> bool:
 def resolve_interpret(interpret: Optional[bool]) -> bool:
     """``None`` -> :func:`default_interpret`; booleans pass through."""
     return default_interpret() if interpret is None else bool(interpret)
+
+
+def require_compiled_kernels() -> None:
+    """Raise when kernels would run interpreted on a TPU backend."""
+    if jax.default_backend() == "tpu" and default_interpret():
+        raise RuntimeError(
+            f"{_ENV}={os.environ.get(_ENV)!r} would run the Pallas kernels "
+            "through the interpreter on a TPU; unset it"
+        )

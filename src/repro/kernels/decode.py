@@ -12,10 +12,10 @@ step's epilogue:
   three heads' accumulators live in scratch), with bias add and RoPE
   rotation fused into the epilogue.
 - :func:`fused_decode_attention` -- single-token GQA attention over the
-  whole KV cache *and* the output projection: per-lane ring/valid/window
-  masking and a streaming-softmax (running max / denom / accumulator)
-  reduction over KV blocks, with ``ctx @ wo + bo`` fused into the final
-  block's epilogue so the (B, Hq, hd) context never round-trips HBM.
+  whole KV cache: per-lane ring/valid/window masking and a
+  streaming-softmax (running max / denom / accumulator) reduction over
+  KV blocks, one grid step per (lane, kv head, KV slab).  The output
+  projection ``ctx @ wo + bo`` follows as one batched XLA GEMM.
 - :func:`fused_mlp` -- the (gated-)MLP as one pass over ``d_ff`` blocks:
   up/gate GEMMs, bias and activation per block, immediately contracted
   through the matching ``w_down`` rows into a (B, d_model) scratch
@@ -75,8 +75,10 @@ def _qkv_kernel(
     bq_ref,         # (1, Dq)
     bk_ref,         # (1, Dkv)
     bv_ref,         # (1, Dkv)
-    sin_ref,        # (B, hd/2) f32
-    cos_ref,        # (B, hd/2) f32
+    cosq_ref,       # (B, Dq) f32 -- per-lane cos of each head's angle
+    sinq_ref,       # (B, Dq) f32 -- per-lane sin, negated on t1's lanes
+    cosk_ref,       # (B, Dkv) f32
+    sink_ref,       # (B, Dkv) f32
     q_ref,          # out (B, Dq)
     k_ref,          # out (B, Dkv)
     v_ref,          # out (B, Dkv)
@@ -125,22 +127,24 @@ def _qkv_kernel(
             v = v + bv_ref[...].astype(dt)
 
         if rope:
-            b = q.shape[0]
             half = head_dim // 2
-            cos = cos_ref[...][:, None, :]           # (B, 1, hd/2)
-            sin = sin_ref[...][:, None, :]
 
-            def rot(t, heads):
-                tf = t.reshape(b, heads, head_dim).astype(jnp.float32)
-                t1 = tf[..., :half]
-                t2 = tf[..., half:]
-                out = jnp.concatenate(
-                    [t1 * cos - t2 * sin, t2 * cos + t1 * sin], axis=-1
+            def rot(t, cos, sin):
+                # per head [t1, t2] -> [t1 cos - t2 sin, t2 cos + t1 sin],
+                # on the flat (B, H*hd) lanes: the partner of a lane is
+                # half a head away, fetched by a lane rotation
+                tf = t.astype(jnp.float32)
+                w = tf.shape[1]
+                lane = jax.lax.broadcasted_iota(jnp.int32, tf.shape, 1)
+                partner = jnp.where(
+                    lane % head_dim < half,
+                    pltpu.roll(tf, w - half, 1),       # t2 under t1
+                    pltpu.roll(tf, half, 1),           # t1 under t2
                 )
-                return out.astype(dt).reshape(b, heads * head_dim)
+                return (tf * cos + partner * sin).astype(dt)
 
-            q = rot(q, n_heads)
-            k = rot(k, n_kv_heads)
+            q = rot(q, cosq_ref[...], sinq_ref[...])
+            k = rot(k, cosk_ref[...], sink_ref[...])
         q_ref[...] = q
         k_ref[...] = k
         v_ref[...] = v
@@ -193,6 +197,13 @@ def fused_qkv(
         sin, cos = jnp.sin(angles), jnp.cos(angles)
     else:
         sin = cos = jnp.zeros((b, head_dim // 2), jnp.float32)
+    # one head's lanes are [cos, cos] and [-sin, sin], tiled over heads
+    cos_h = jnp.concatenate([cos, cos], axis=-1)
+    sin_h = jnp.concatenate([-sin, sin], axis=-1)
+    tables = [
+        jnp.tile(t, (1, heads))
+        for heads in (n_heads, n_kv_heads) for t in (cos_h, sin_h)
+    ]
 
     zq = jnp.zeros((1, dq), dt)
     zkv = jnp.zeros((1, dkv), dt)
@@ -221,8 +232,10 @@ def fused_qkv(
             pl.BlockSpec((1, dq), lambda j: (0, 0)),
             pl.BlockSpec((1, dkv), lambda j: (0, 0)),
             pl.BlockSpec((1, dkv), lambda j: (0, 0)),
-            pl.BlockSpec((b, head_dim // 2), lambda j: (0, 0)),
-            pl.BlockSpec((b, head_dim // 2), lambda j: (0, 0)),
+            pl.BlockSpec((b, dq), lambda j: (0, 0)),
+            pl.BlockSpec((b, dq), lambda j: (0, 0)),
+            pl.BlockSpec((b, dkv), lambda j: (0, 0)),
+            pl.BlockSpec((b, dkv), lambda j: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((b, dq), lambda j: (0, 0)),
@@ -240,7 +253,7 @@ def fused_qkv(
             pltpu.VMEM((b, dkv), jnp.float32),
         ],
         interpret=interpret,
-    )(xp, wqp, wkp, wvp, bq2, bk2, bv2, sin, cos)
+    )(xp, wqp, wkp, wvp, bq2, bk2, bv2, *tables)
     return (
         q.reshape(b, n_heads, head_dim),
         k.reshape(b, n_kv_heads, head_dim),
@@ -248,34 +261,38 @@ def fused_qkv(
     )
 
 
-# -------------------------------------------- fused decode attention + wo --
+# ------------------------------------------------- fused decode attention --
+
+
+def heads_per_slab(n_kv_heads: int, head_dim: int) -> int:
+    """KV heads one attention grid step reads: the fewest whose hd-wide
+    lane slabs fill whole 128-lane tiles, or all of them (a full-width
+    block is always tile-legal)."""
+    for p in range(1, n_kv_heads):
+        if n_kv_heads % p == 0 and p * head_dim % 128 == 0:
+            return p
+    return n_kv_heads
 
 
 def _decode_attn_kernel(
-    q_ref,          # (1, Hq, hd)
-    k_ref,          # (1, bs, Hkv, hd)
-    v_ref,          # (1, bs, Hkv, hd)
+    limit_ref,      # SMEM (B,) int32 -- per-lane valid length
+    row_ref,        # SMEM (B,) int32 -- query position
+    win_ref,        # SMEM (1,) int32 -- attention window
+    q_ref,          # (P*G, P*hd) pre-scaled, block-diagonal queries of P heads
+    k_ref,          # (bs, P*hd) key slab of P kv heads
+    v_ref,          # (bs, P*hd)
     col_ref,        # (1, bs) int32 -- absolute position per cache slot
-    limit_ref,      # (1, 1) int32 -- per-lane valid length
-    row_ref,        # (1, 1) int32 -- query position
-    win_ref,        # (1, 1) int32 -- attention window
-    wo_ref,         # (Hq*hd, d)
-    bo_ref,         # (1, d)
-    out_ref,        # (1, d)
-    m_ref,          # scratch (Hkv, G) f32 -- running max
-    l_ref,          # scratch (Hkv, G) f32 -- running denom
-    acc_ref,        # scratch (Hkv, G, hd) f32 -- running PV accumulator
+    o_ref,          # out (P*G, P*hd) -- context in the diagonal blocks
+    m_ref,          # scratch (P*G, 1) f32 -- running max
+    l_ref,          # scratch (P*G, 1) f32 -- running denom
+    acc_ref,        # scratch (P*G, P*hd) f32 -- running PV accumulator
     *,
     n_s: int,
-    n_kv_heads: int,
-    groups: int,
-    head_dim: int,
-    scale: float,
     causal: bool,
     use_kvp: bool,
-    has_bias: bool,
 ):
-    s_idx = pl.program_id(1)
+    i = pl.program_id(0)
+    s_idx = pl.program_id(2)
 
     @pl.when(s_idx == 0)
     def _init():
@@ -283,62 +300,39 @@ def _decode_attn_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                                   # (Hq, hd)
-    qs = (q * scale).astype(q.dtype)
-    kb = k_ref[0]                                  # (bs, Hkv, hd)
-    vb = v_ref[0]
-
-    col = col_ref[0]                               # (bs,)
+    col = col_ref[...]                             # (1, bs)
     if use_kvp:
         # ring buffer: each slot carries its absolute position; negative
         # positions mark never-written slots (padded slots carry _PAD_NEG)
         valid = col >= 0
     else:
-        valid = col < limit_ref[0, 0]
+        valid = col < limit_ref[i]
     if causal:
-        row = row_ref[0, 0]
-        win = win_ref[0, 0]
-        valid = valid & (col <= row) & (col > row - win)
+        row = row_ref[i]
+        valid = valid & (col <= row) & (col > row - win_ref[0])
 
-    # per-kv-head streaming-softmax update (static unroll: Hkv is small and
-    # keeps every dot rank-2 for the MXU)
-    m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
-    m_rows, l_rows, acc_rows = [], [], []
-    cdims = (((1,), (1,)), ((), ()))               # (G,hd) x (bs,hd)^T
-    pdims = (((1,), (0,)), ((), ()))               # (G,bs) x (bs,hd)
-    for kh in range(n_kv_heads):
-        qh = qs[kh * groups:(kh + 1) * groups]     # (G, hd)
-        s = jax.lax.dot_general(
-            qh, kb[:, kh, :], cdims, preferred_element_type=jnp.float32
-        )                                          # (G, bs)
-        s = jnp.where(valid[None, :], s, _NEG)
-        m_c = jnp.max(s, axis=-1)                  # (G,)
-        m_new = jnp.maximum(m_prev[kh], m_c)
-        corr = jnp.exp(m_prev[kh] - m_new)
-        p = jnp.exp(s - m_new[:, None])            # (G, bs)
-        l_rows.append(l_prev[kh] * corr + jnp.sum(p, axis=-1))
-        pv = jax.lax.dot_general(
-            p.astype(vb.dtype), vb[:, kh, :], pdims,
-            preferred_element_type=jnp.float32,
-        )                                          # (G, hd)
-        acc_rows.append(acc_prev[kh] * corr[:, None] + pv)
-        m_rows.append(m_new)
-    m_ref[...] = jnp.stack(m_rows)
-    l_ref[...] = jnp.stack(l_rows)
-    acc_ref[...] = jnp.stack(acc_rows)
+    s = jax.lax.dot_general(                       # (PG, Phd) x (bs, Phd)^T
+        q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                              # (PG, bs)
+    s = jnp.where(valid, s, _NEG)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                         # (PG, bs)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(                      # (PG, bs) x (bs, Phd)
+        p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    acc_ref[...] = acc_ref[...] * corr + pv
+    m_ref[...] = m_new
 
     @pl.when(s_idx == n_s - 1)
     def _epilogue():
-        dt = out_ref.dtype
-        ctx = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-        ctx = ctx.astype(dt).reshape(1, n_kv_heads * groups * head_dim)
-        y = jax.lax.dot_general(
-            ctx, wo_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dt)
-        if has_bias:
-            y = y + bo_ref[...].astype(dt)
-        out_ref[...] = y
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+            o_ref.dtype
+        )
 
 
 @functools.partial(
@@ -365,20 +359,22 @@ def fused_decode_attention(
 
     Mask semantics mirror ``models.attention._decode_attention`` exactly:
     ``kv_positions`` (ring caches; negative = never written) else
-    ``arange < kv_valid_len``; causal row/window bounds on top.  The KV
-    axis is streamed in ``block_s`` slabs with a running
-    (max, denom, accumulator) softmax, and ``ctx @ wo (+ bo)`` runs in the
-    last slab's epilogue.
+    ``arange < kv_valid_len``; causal row/window bounds on top.  The
+    kernel's grid is (lane, kv head pack, KV slab): each step streams the
+    ``block_s`` slots of K and V of ``P = heads_per_slab(Hkv, hd)`` kv
+    heads and updates a running (max, denom, accumulator) softmax for
+    their query groups.  ``ctx @ wo (+ bo)`` is one (B, Hq*hd) GEMM in
+    XLA after the kernel (DESIGN.md SS10).
     """
     interpret = resolve_interpret(interpret)
     b, hq, hd = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     groups = hq // hkv
+    pack = heads_per_slab(hkv, hd)
+    n_pack = hkv // pack
+    rows, lanes = pack * groups, pack * hd
     dt = q.dtype
-    d = wo.shape[1]
-    has_bias = bo is not None
     use_kvp = kv_positions is not None
-    scale = 1.0 / (hd ** 0.5)
 
     if block_s is None:
         block_s = min(sk, 512)
@@ -392,55 +388,66 @@ def fused_decode_attention(
     else:
         col = jnp.broadcast_to(jnp.arange(sk, dtype=jnp.int32)[None], (b, sk))
         pad_val = _PAD_POS
-    col = _pad_axis(col, 1, block_s, value=pad_val)
-    kp = _pad_axis(k, 1, block_s)
-    vp = _pad_axis(v, 1, block_s)
+    col = _pad_axis(col, 1, block_s, value=pad_val)[:, None, :]
+    # (B, Sk, Hkv, hd) -> (B, Sk, Hkv*hd): P kv heads make one lane slab
+    kp = _pad_axis(k, 1, block_s).reshape(b, -1, hkv * hd)
+    vp = _pad_axis(v, 1, block_s).reshape(b, -1, hkv * hd)
     n_s = kp.shape[1] // block_s
 
     limit = jnp.broadcast_to(
         jnp.asarray(sk if kv_valid_len is None else kv_valid_len, jnp.int32),
         (b,),
-    ).reshape(b, 1)
-    row = q_positions.astype(jnp.int32).reshape(b, 1)
+    )
+    row = q_positions.astype(jnp.int32).reshape(b)
     if window_arr is not None:
         win = jnp.asarray(window_arr, jnp.int32)
     elif window is not None:
         win = jnp.asarray(window, jnp.int32)
     else:
         win = jnp.asarray(_BIG, jnp.int32)
-    win = win.reshape(1, 1)
+    win = win.reshape(1)
+    # the query scale rounds in the compute dtype, as in the XLA path
+    qs = (q * (1.0 / (hd ** 0.5))).reshape(b, n_pack, pack, groups, hd)
+    if pack > 1:
+        # block-diagonal: head p's queries see only head p's hd lanes
+        eye = jnp.eye(pack, dtype=bool)[:, None, :, None]
+        qs = jnp.where(eye, qs[:, :, :, :, None, :], 0)
+    qs = qs.reshape(b, n_pack, rows, lanes)
 
-    wo_dt = wo.astype(dt)
-    bo2 = bo.reshape(1, d).astype(dt) if has_bias else jnp.zeros((1, d), dt)
-
-    out = pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    group_spec = pl.BlockSpec((None, None, rows, lanes), lambda i, h, s: (i, h, 0, 0))
+    kv_spec = pl.BlockSpec((None, block_s, lanes), lambda i, h, s: (i, s, h))
+    ctx = pl.pallas_call(
         functools.partial(
-            _decode_attn_kernel, n_s=n_s, n_kv_heads=hkv, groups=groups,
-            head_dim=hd, scale=scale, causal=causal, use_kvp=use_kvp,
-            has_bias=has_bias,
+            _decode_attn_kernel, n_s=n_s, causal=causal, use_kvp=use_kvp
         ),
-        grid=(b, n_s),
+        grid=(b, n_pack, n_s),
         in_specs=[
-            pl.BlockSpec((1, hq, hd), lambda i, s: (i, 0, 0)),
-            pl.BlockSpec((1, block_s, hkv, hd), lambda i, s: (i, s, 0, 0)),
-            pl.BlockSpec((1, block_s, hkv, hd), lambda i, s: (i, s, 0, 0)),
-            pl.BlockSpec((1, block_s), lambda i, s: (i, s)),
-            pl.BlockSpec((1, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, s: (0, 0)),
-            pl.BlockSpec((hq * hd, d), lambda i, s: (0, 0)),
-            pl.BlockSpec((1, d), lambda i, s: (0, 0)),
+            smem, smem, smem,
+            group_spec,
+            kv_spec,
+            kv_spec,
+            pl.BlockSpec((None, 1, block_s), lambda i, h, s: (i, 0, s)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, s: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, d), dt),
+        out_specs=group_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n_pack, rows, lanes), dt),
         scratch_shapes=[
-            pltpu.VMEM((hkv, groups), jnp.float32),
-            pltpu.VMEM((hkv, groups), jnp.float32),
-            pltpu.VMEM((hkv, groups, hd), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, lanes), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(q, kp, vp, col, limit, row, win, wo_dt, bo2)
-    return out
+    )(limit, row, win, qs, kp, vp, col)
+    if pack > 1:
+        ctx = ctx.reshape(b, n_pack, pack, groups, pack, hd)
+        ctx = jnp.moveaxis(jnp.diagonal(ctx, axis1=2, axis2=4), -1, 2)
+    y = ctx.reshape(b, hq * hd) @ wo.astype(dt)
+    if bo is not None:
+        y = y + bo.astype(dt)
+    return y
 
 
 # -------------------------------------------------------------- fused MLP --
@@ -468,30 +475,36 @@ def _mlp_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     dt = out_ref.dtype
+    f32 = jnp.float32
     xb = x_ref[...]
     dims = (((1,), (0,)), ((), ()))
+
+    def rnd(t):
+        # one rounding to the compute dtype, kept in f32: the TPU vector
+        # unit has no bf16 arithmetic, so the activation runs in f32 and
+        # rounds after every op the XLA path rounds after
+        return t.astype(dt).astype(f32)
+
     # d_model is unblocked, so each f-slab's up/gate columns complete in one
     # dot -- rounding to the compute dtype here is exactly the XLA dot's
-    g = jax.lax.dot_general(
-        xb, wg_ref[...], dims, preferred_element_type=jnp.float32
-    ).astype(dt)
+    g = rnd(jax.lax.dot_general(xb, wg_ref[...], dims, preferred_element_type=f32))
     if has_bias:
-        g = g + bu_ref[...].astype(dt)
+        g = rnd(g + bu_ref[...].astype(f32))
     if gated:
-        up = jax.lax.dot_general(
-            xb, wu_ref[...], dims, preferred_element_type=jnp.float32
-        ).astype(dt)
+        up = rnd(
+            jax.lax.dot_general(xb, wu_ref[...], dims, preferred_element_type=f32)
+        )
     if act == "swiglu":
-        h = jax.nn.silu(g) * up
+        h = rnd(g / (1.0 + jnp.exp(-g))) * up
     elif act == "gelu":
         h = jax.nn.gelu(g)
     elif act == "sq_relu":
-        r = jax.nn.relu(g)
+        r = jnp.maximum(g, 0.0)
         h = r * r
     else:
         raise ValueError(act)
     acc_ref[...] += jax.lax.dot_general(
-        h.astype(dt), wd_ref[...], dims, preferred_element_type=jnp.float32
+        h.astype(dt), wd_ref[...], dims, preferred_element_type=f32
     )
 
     @pl.when(j == n_f - 1)

@@ -88,8 +88,9 @@ def kernel_blocks(cfg, *, sk: Optional[int] = None, dtype=jnp.bfloat16) -> dict:
         "block_f": _slab(cfg.d_ff, 3 * d * it),
     }
     if sk is not None:
-        # attention streams KV slots (k and v slabs per slot)
-        out["block_s"] = _slab(sk, 2 * cfg.n_kv_heads * hd * it)
+        # attention streams one lane slab of kv heads' k and v per grid step
+        lanes = decode.heads_per_slab(cfg.n_kv_heads, hd) * hd
+        out["block_s"] = _slab(sk, 2 * lanes * it)
     return out
 
 

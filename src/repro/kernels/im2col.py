@@ -9,9 +9,14 @@ grid step gathers the strided rows of one output-row block from the (padded)
 feature map in VMEM and emits the corresponding patch-matrix rows.
 
 Grid: one program per output row (OH); each program emits the (OW, k*k*C)
-patch block for that row, assembled from k*k strided slices -- a direct
-transcription of the "address and length bundles ... according to the IFM
-dimensions and Conv characteristics".
+patch block for that row as k*k contiguous (OW, C) segments, each stored
+at its own lane offset -- a direct transcription of the "address and
+length bundles ... according to the IFM dimensions and Conv
+characteristics".  A stride above 1 is taken out of the gather before the
+kernel: the padded map is split into ``stride`` column phases, so column
+``kj + stride*o`` is column ``kj // stride + o`` of phase ``kj % stride``
+and every segment the kernel reads is contiguous (Mosaic has no strided
+sublane slice).
 """
 from __future__ import annotations
 
@@ -26,21 +31,15 @@ from repro.kernels.common import resolve_interpret
 
 
 def _im2col_kernel(img_ref, out_ref, *, k: int, stride: int, ow: int, c: int):
-    oh_idx = pl.program_id(0)
-    base = oh_idx * stride
-    pieces = []
+    base = pl.program_id(0) * stride
     for ki in range(k):
-        row = img_ref[base + ki]          # (Wp, C) -- one feature-map row
         for kj in range(k):
-            # Strided gather of OW segments of C channels: the DMA command
-            # bundle for (ki, kj) of this output row.
-            sl = jax.lax.slice(
-                row, (kj, 0), (kj + (ow - 1) * stride + 1, c), (stride, 1)
-            )                              # (OW, C)
-            pieces.append(sl)
-    # Patch layout: [(ki, kj) outer, C inner] -- matches weight reshape
-    # w4d.transpose(3,0,1,2).reshape(Cout, k*k*Cin).
-    out_ref[0] = jnp.stack(pieces, axis=1).reshape(ow, k * k * c)
+            idx = ki * k + kj
+            # Patch layout: [(ki, kj) outer, C inner] -- matches weight
+            # reshape w4d.transpose(3,0,1,2).reshape(Cout, k*k*Cin).
+            out_ref[0, :, idx * c:(idx + 1) * c] = img_ref[
+                kj % stride, base + ki, pl.ds(kj // stride, ow), :
+            ]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "stride", "pad", "interpret"))
@@ -58,9 +57,13 @@ def im2col(
     """
     interpret = resolve_interpret(interpret)
     h, w, c = img.shape
-    imgp = jnp.pad(img, ((pad, pad), (pad, pad), (0, 0)))
     oh = (h + 2 * pad - k) // stride + 1
     ow = (w + 2 * pad - k) // stride + 1
+    # pad the width to a whole number of phases, then split the columns
+    # into their stride phases: (stride, Hp, Wp/stride, C)
+    wp = -(-(w + 2 * pad) // stride) * stride
+    imgp = jnp.pad(img, ((pad, pad), (pad, wp - w - pad), (0, 0)))
+    phases = imgp.reshape(h + 2 * pad, wp // stride, stride, c).transpose(2, 0, 1, 3)
 
     out = pl.pallas_call(
         functools.partial(_im2col_kernel, k=k, stride=stride, ow=ow, c=c),
@@ -69,5 +72,5 @@ def im2col(
         out_specs=pl.BlockSpec((1, ow, k * k * c), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((oh, ow, k * k * c), img.dtype),
         interpret=interpret,
-    )(imgp)
+    )(phases)
     return out.reshape(oh * ow, k * k * c)

@@ -42,10 +42,12 @@ def _mix(x: jax.Array) -> jax.Array:
 
 
 def _uniform(counter: jax.Array, salt: int) -> jax.Array:
-    """(0,1) floats from the counter hash (uint32 bits / 2^32)."""
+    """(0,1) floats from the counter hash: the top 24 bits, centred in
+    their bucket.  24 bits are exact in float32 and go through int32,
+    which Mosaic can convert (it has no uint32 -> float32 cast)."""
     bits = _mix(counter ^ jnp.uint32(salt))
-    u = bits.astype(jnp.float32) / jnp.float32(2**32)
-    return jnp.clip(u, 1e-7, 1.0 - 1e-7)
+    hi = jax.lax.bitcast_convert_type(bits >> 8, jnp.int32)
+    return (hi.astype(jnp.float32) + 0.5) * (1.0 / 2**24)
 
 
 def _gaussian(counter: jax.Array, salt: int) -> jax.Array:

@@ -130,9 +130,6 @@ def run_cell(
 
             mem = compiled.memory_analysis()      # proves it fits
             cost = compiled.cost_analysis()       # raw XLA view (recorded)
-            # jax < 0.5 returns one properties dict per program in a list
-            if isinstance(cost, list):
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
             # Scan-aware global FLOPs from the jaxpr (see analysis docstring)
             flops_global = step_flops(step_fn, specs)

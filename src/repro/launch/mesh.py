@@ -6,60 +6,27 @@ only launch/dryrun.py may set the 512-placeholder-device XLA flag.
 """
 from __future__ import annotations
 
-import enum
-import inspect
-
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: sharding-in-types axis kinds
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every mesh axis is implicitly "auto"
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-# jax.make_mesh only grew `axis_types` alongside AxisType itself; probe the
-# signature once so both call sites below stay version-agnostic.
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
-def _make_mesh(shape, axes) -> Mesh:
-    if _MAKE_MESH_HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def abstract_mesh(shape, axes):
-    """Device-free mesh for shape/sharding reasoning (tests, dry-run).
-
-    jax >= 0.5 spells it ``AbstractMesh(axis_sizes, axis_names)``; 0.4.x
-    wanted one ``((name, size), ...)`` tuple.  Try modern first.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(tuple(axes), tuple(shape))))
+def abstract_mesh(shape, axes) -> AbstractMesh:
+    """Device-free mesh for shape/sharding reasoning (tests, dry-run)."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """Single-pod (data=16, model=16) = 256 chips, or two pods = 512."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes) -> Mesh:
     """Arbitrary mesh (tests / elastic re-shard / hillclimb variants)."""
-    return _make_mesh(shape, axes)
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def single_device_mesh() -> Mesh:

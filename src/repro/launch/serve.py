@@ -29,6 +29,7 @@ emulation, refreshing weights with fresh PCM-style noise every round.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 
 import numpy as np
@@ -39,12 +40,13 @@ from repro.analysis import sanitize
 from repro.configs import ARCH_IDS, get_config, smoke_variant
 from repro.core.aimc import AIMCNoiseModel
 from repro.core.pu import host_offload_config, tpu_v5e_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api as model_api
 from repro.plan import SearchConfig
 from repro.runtime.serving import ServeConfig, ServingEngine
 
 
-def main() -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -107,14 +109,21 @@ def main() -> int:
     ap.add_argument("--aimc", action="store_true",
                     help="AIMC noise emulation (SS VI NIU)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
+
+def run(argv=None) -> ServingEngine:
+    """Build the engine for ``argv``, warm it, serve the seeded traffic
+    until drained, and return the drained engine."""
+    args = build_parser().parse_args(argv)
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_variant(cfg)
 
     api = model_api.get_api(cfg)
-    params = api.init_params(cfg, jax.random.PRNGKey(args.seed))
+    params = jax.jit(functools.partial(api.init_params, cfg))(
+        jax.random.PRNGKey(args.seed)
+    )
 
     serve_cfg = ServeConfig(
         max_batch=args.max_batch,
@@ -170,8 +179,13 @@ def main() -> int:
         engine.execute_partition(
             n_microbatches=args.microbatches or None
         )
-    stats = engine.stats()
-    print(json.dumps(stats, indent=1, default=float))
+    return engine
+
+
+def main(argv=None) -> int:
+    enable_compile_cache()
+    engine = run(argv)
+    print(json.dumps(engine.stats(), indent=1, default=float))
     if sanitize.enabled():
         violations = sanitize.lock_violations()
         for v in violations:

@@ -18,6 +18,7 @@ import jax
 
 from repro.configs import ARCH_IDS, SHAPES_BY_NAME, get_config, smoke_variant
 from repro.configs.base import ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh, single_device_mesh
 from repro.optim import AdamWConfig
 from repro.parallel.sharding import NAMED_RULES
@@ -32,7 +33,7 @@ def parse_mesh(spec: str):
     return make_mesh(sizes, axes)
 
 
-def main() -> int:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--shape", default="train_4k", choices=sorted(SHAPES_BY_NAME))
@@ -50,7 +51,8 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--crash-at", type=int, default=None,
                     help="failure-injection drill: crash at this step")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     shape = SHAPES_BY_NAME[args.shape]

@@ -163,7 +163,6 @@ def _moe_apply_local(cfg, p, x, mesh, shard_axes):
     """shard_map dispatch with (batch-axes, seq-axes) token sharding:
     tokens stay on their devices; expert weights enter replicated (GSPMD
     gathers the ZeRO shards at the boundary)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes0, axes1 = shard_axes
@@ -184,12 +183,12 @@ def _moe_apply_local(cfg, p, x, mesh, shard_axes):
             y, aux = _moe_apply_global(cfg, p_local, x_local)
         return y, aux.reshape(1)
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(w_spec, x_spec),
         out_specs=(x_spec, aux_spec),
-        check_rep=False,
+        check_vma=False,
     )(p, x)
     return y, jnp.mean(aux)
 

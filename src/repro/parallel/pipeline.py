@@ -32,7 +32,6 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:
@@ -125,12 +124,12 @@ def pipeline_apply(
         outputs = jax.lax.psum(outputs * mask, stage_axis)
         return outputs
 
-    runner = shard_map(
+    runner = jax.shard_map(
         stage_prog,
         mesh=mesh,
         in_specs=in_spec,
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     out_mb = runner(stacked_params, mb)
     return out_mb.reshape(x.shape)

@@ -56,6 +56,7 @@ from repro.configs.base import ModelConfig
 from repro.core.aimc import AIMCNoiseModel, NoiseInjectionUnit
 from repro.core.pu import PUConfig, host_offload_config
 from repro.core.streaming import StreamingPlan, WeightTile, plan_streaming
+from repro.kernels.common import require_compiled_kernels
 from repro.models import api as model_api
 from repro.plan import (
     PartitionedPlan,
@@ -183,6 +184,7 @@ class ServingEngine:
         mesh=None,
         rules=None,
     ):
+        require_compiled_kernels()
         if serve_cfg.decode_kernels and not cfg.decode_kernels:
             cfg = dataclasses.replace(cfg, decode_kernels=True)
         self.cfg = cfg
@@ -204,6 +206,8 @@ class ServingEngine:
         self._slot_emitted = np.zeros(serve_cfg.max_batch, np.int32)
         self.completed: List[Request] = []
         self.rounds = 0
+        self.warmup_s: Optional[float] = None
+        self._warm_traces: Optional[int] = None
 
         # batched KV cache for all slots
         self._cache = self.api.init_cache(
@@ -415,7 +419,25 @@ class ServingEngine:
         is untouched -- except the sampling PRNG stream, which each
         warmup call advances exactly like a live call when
         ``temperature > 0`` (the engine stays deterministic for a fixed
-        warmup + traffic sequence)."""
+        warmup + traffic sequence).
+
+        Records ``warmup_s`` (wall time, compilation included, until the
+        device is idle) and the trace count it leaves, so :meth:`stats`
+        reports the traces taken after warmup."""
+        t0 = time.perf_counter()
+        self._warmup()
+        jax.block_until_ready((self._cache, self._state))
+        self.warmup_s = time.perf_counter() - t0
+        self._warm_traces = self.tracing.total()
+
+    def compiled_decode_block(self, n_rounds: int = 1):
+        """The compiled fused decode block of ``n_rounds`` rounds -- the
+        program the device path runs per block, for inspecting its HLO."""
+        return self._decode_block.lower(
+            self.params, self._cache, self._state, n_rounds
+        ).compile()
+
+    def _warmup(self):
         sc = self.serve_cfg
         if sc.host_sampling:
             tokens = jnp.zeros((sc.max_batch, 1), jnp.int32)
@@ -1036,6 +1058,11 @@ class ServingEngine:
             "decode_traces": float(self.trace_counts["decode"]),
             "prefill_traces": float(self.trace_counts["prefill"]),
         }
+        if self._warm_traces is not None:
+            out["warmup_s"] = self.warmup_s
+            out["retraces_after_warmup"] = float(
+                self.tracing.total() - self._warm_traces
+            )
         for b, times in sorted(self.prefill_bucket_s.items()):
             out[f"prefill_s_bucket{b}"] = float(np.mean(times))
         if self.streaming_plan is not None:

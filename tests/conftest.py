@@ -7,12 +7,6 @@ import pytest
 
 import jax
 
-# Property tests use hypothesis; the pinned container has no wheel for it.
-# Install the in-repo fallback runner iff the real package is missing.
-from repro._compat import hypothesis_fallback
-
-hypothesis_fallback.install()
-
 
 @pytest.fixture(scope="session")
 def rng():

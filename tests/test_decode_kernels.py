@@ -27,6 +27,7 @@ from repro.kernels import (
     fused_qkv,
     fused_qkv_ref,
 )
+from repro.kernels.decode import heads_per_slab
 from repro.models import api as model_api
 from repro.models import attention as attn
 from repro.runtime.serving import ServeConfig, ServingEngine
@@ -128,6 +129,28 @@ def test_fused_attention_matches_ref(tensors, rng, case):
         t["q"], t["k"], t["v"], t["wo"], t["bo"], q_positions=t["qpos"], **kw
     )
     _close(got, want)
+
+
+@pytest.mark.parametrize(
+    "hkv,hd,pack", [(4, 64, 2), (2, 128, 1), (3, 96, 3), (2, 32, 2)]
+)
+def test_fused_attention_head_packing(rng, hkv, hd, pack):
+    """Heads narrower than a 128-lane tile are read ``pack`` to a slab,
+    each head's queries masked to its own lanes: the context stays per
+    head, whatever the packing."""
+    assert heads_per_slab(hkv, hd) == pack
+    hq = 2 * hkv
+    n = lambda *s: rng.normal(size=s)
+    q = jnp.asarray(n(B, hq, hd), _DT)
+    k = jnp.asarray(n(B, SK, hkv, hd), _DT)
+    v = jnp.asarray(n(B, SK, hkv, hd), _DT)
+    wo = jnp.asarray(n(hq * hd, D) * 0.05, jnp.float32)
+    kw = dict(
+        q_positions=jnp.asarray([5, 20, 39], jnp.int32),
+        kv_valid_len=jnp.asarray([6, 21, 40], jnp.int32),
+    )
+    got = fused_decode_attention(q, k, v, wo, None, block_s=16, **kw)
+    _close(got, decode_attention_ref(q, k, v, wo, None, **kw))
 
 
 @pytest.mark.parametrize(

@@ -105,7 +105,6 @@ def test_int8_psum_mean():
     out = run_py("""
         import functools
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
         from repro.parallel.compression import int8_psum
@@ -113,7 +112,7 @@ def test_int8_psum_mean():
         mesh = make_mesh((4,), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
 
-        f = shard_map(
+        f = jax.shard_map(
             lambda v: int8_psum(v[0], "data")[None],
             mesh=mesh, in_specs=P("data"), out_specs=P("data"),
         )
