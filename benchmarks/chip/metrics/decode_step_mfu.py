@@ -1,0 +1,17 @@
+"""Model operations of the decode rounds (active lanes only,
+work.decode_flops) over the decode-block device time at the bf16 peak:
+the whole decode program's share of the chip's peak."""
+
+from benchmarks.chip import work
+
+
+def read(run):
+    prog = run.program_seconds()
+    if prog is None or prog["decode"] <= 0:
+        return None
+    flops = sum(
+        work.decode_flops(run.dims, lanes)
+        for i in prog["steps"]
+        for lanes in run.lane_positions(i)
+    )
+    return 100.0 * flops / run.peaks["bf16_flop_per_s"] / prog["decode"]
