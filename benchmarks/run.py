@@ -801,8 +801,7 @@ def bench_serve_suite(fast: bool):
                 "greedy_bit_identical": host_streams == dev_streams,
                 "bit_gated": arch in bit_gated,
                 "prefill_s_by_bucket": {
-                    str(b): float(np.mean(ts))
-                    for b, ts in sorted(dev.prefill_bucket_s.items())
+                    str(b): s for b, s in dev.prefill_s_by_bucket().items()
                 },
                 "decode_traces_total": dev.trace_counts["decode"],
                 "prefill_traces_total": dev.trace_counts["prefill"],

@@ -79,6 +79,12 @@ class TraceCounter:
             self.bump(kind)
             return fn(*args, **kwargs)
 
+        # jit names its program after the function, so a profiler trace
+        # shows ``jit_<fn name>`` and not ``jit_traced``.  Set by hand:
+        # ``functools.wraps`` would add ``__wrapped__``, through which jit
+        # reads ``fn``'s signature and resolves argnums against it.
+        traced.__name__ = getattr(fn, "__name__", traced.__name__)
+        traced.__qualname__ = getattr(fn, "__qualname__", traced.__qualname__)
         return traced
 
     def jit(self, fn, *, kind: str, **jit_kwargs):

@@ -50,6 +50,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.analysis import sanitize
 from repro.configs.base import ModelConfig
@@ -131,6 +132,22 @@ class ServeConfig:
     decode_kernels: bool = False
 
 
+@dataclasses.dataclass(frozen=True)
+class Admission:
+    """One batched prefill call of the device path: the requests of one
+    length bucket admitted together (the args of its ``serve.admit``
+    span).  Each request it admitted keeps it as ``Request.admission``."""
+    bucket: int        # prompt positions computed per row
+    n: int             # requests admitted
+    n_pad: int         # rows computed: n padded to a power of two
+    tokens: int        # real prompt tokens
+
+    @property
+    def computed(self) -> int:
+        """Prompt positions the prefill program computed, padding included."""
+        return self.bucket * self.n_pad
+
+
 @dataclasses.dataclass
 class Request:
     uid: int
@@ -140,6 +157,7 @@ class Request:
     submitted_at: float = 0.0
     first_token_at: Optional[float] = None
     done_at: Optional[float] = None
+    admission: Optional[Admission] = None
 
     @property
     def ttft_s(self) -> Optional[float]:
@@ -221,8 +239,17 @@ class ServingEngine:
         # for stats() and the benchmarks.
         self.tracing = sanitize.TraceCounter(("decode", "prefill"))
         self.trace_counts: Dict[str, int] = self.tracing.counts
-        # wall-clock per admitted prefill call, keyed by bucket length
-        self.prefill_bucket_s: Dict[int, List[float]] = {}
+        # admitted prefill calls and their wall-clock seconds (dispatch to
+        # host sync), keyed by bucket length
+        self.prefill_calls: Dict[int, int] = {}
+        self.prefill_seconds: Dict[int, float] = {}
+        # device-path counters: real prompt tokens prefilled, the prompt
+        # positions computed for them (bucket x padded rows), decode
+        # blocks run, and active lanes x rounds over those blocks
+        self.prefill_tokens = 0
+        self.prefill_tokens_computed = 0
+        self.decode_blocks = 0
+        self.busy_lane_rounds = 0
 
         # ring caches re-layout the whole sequence at prefill time, which
         # does not compose with per-lane padded lengths; recurrent
@@ -509,7 +536,8 @@ class ServingEngine:
         if sc.host_sampling:
             self._step_host()
         else:
-            self._step_device()
+            with TraceAnnotation("serve.step", queue=len(self._queue), lanes=self.active):
+                self._step_device()
 
     # ======================================================================
     # device-resident path
@@ -790,30 +818,35 @@ class ServingEngine:
             # pad the admit batch to a power of two so the (bucket, nb)
             # trace set stays bounded; dummy rows scatter out of bounds
             nb_pad = _pow2_ceil(nb) if self.bucketed_prefill else nb
-            tokens = np.full((nb_pad, S), sc.pad_token, np.int32)
-            lengths = np.ones((nb_pad,), np.int32)
-            slots = np.full((nb_pad,), sc.max_batch, np.int32)
-            max_new = np.ones((nb_pad,), np.int32)
+            adm = Admission(S, nb, nb_pad, sum(len(p) for _, _, p in group))
+            with TraceAnnotation("serve.admit", bucket=S, n=nb, n_pad=nb_pad,
+                                 tokens=adm.tokens):
+                tokens = np.full((nb_pad, S), sc.pad_token, np.int32)
+                lengths = np.ones((nb_pad,), np.int32)
+                slots = np.full((nb_pad,), sc.max_batch, np.int32)
+                max_new = np.ones((nb_pad,), np.int32)
+                for j, (slot, req, prompt) in enumerate(group):
+                    tokens[j, : len(prompt)] = prompt
+                    lengths[j] = len(prompt)
+                    slots[j] = slot
+                    max_new[j] = req.max_new_tokens
+                t0 = time.perf_counter()
+                self._cache, self._state, tok, done0 = self._admit_block(
+                    self.params, self._cache, self._state,
+                    jnp.asarray(tokens), jnp.asarray(lengths),
+                    jnp.asarray(slots), jnp.asarray(max_new),
+                )
+            with TraceAnnotation("serve.admit_sync"):
+                # lint: disable=RPL002 -- designed admission-boundary sync: the admit block must land before slots update
+                done0_np = np.asarray(done0)
+                self._time_prefill(S, time.perf_counter() - t0)
+                now = time.perf_counter()
+                # lint: disable=RPL002 -- designed admission-boundary sync: first tokens of already-done admits drain here
+                tok_np = np.asarray(tok) if done0_np[:nb].any() else None
+            self.prefill_tokens += adm.tokens
+            self.prefill_tokens_computed += adm.computed
             for j, (slot, req, prompt) in enumerate(group):
-                tokens[j, : len(prompt)] = prompt
-                lengths[j] = len(prompt)
-                slots[j] = slot
-                max_new[j] = req.max_new_tokens
-            t0 = time.perf_counter()
-            self._cache, self._state, tok, done0 = self._admit_block(
-                self.params, self._cache, self._state,
-                jnp.asarray(tokens), jnp.asarray(lengths),
-                jnp.asarray(slots), jnp.asarray(max_new),
-            )
-            # lint: disable=RPL002 -- designed admission-boundary sync: the admit block must land before slots update
-            done0_np = np.asarray(done0)
-            self.prefill_bucket_s.setdefault(S, []).append(
-                time.perf_counter() - t0
-            )
-            now = time.perf_counter()
-            # lint: disable=RPL002 -- designed admission-boundary sync: first tokens of already-done admits drain here
-            tok_np = np.asarray(tok) if done0_np[:nb].any() else None
-            for j, (slot, req, prompt) in enumerate(group):
+                req.admission = adm
                 req.first_token_at = now
                 if done0_np[j]:
                     # lint: disable=RPL002 -- host-side numpy scalar; the batch already drained above
@@ -853,6 +886,7 @@ class ServingEngine:
         r = min(remaining) if self._queue else max(remaining)
         r = max(1, min(r, cap))
         R = 1 << (r.bit_length() - 1)          # largest power of two <= r
+        lanes = len(remaining)
         # the decode block itself must never pull device data to the
         # host: under REPRO_SANITIZE=1 an implicit device->host transfer
         # inside it raises instead of silently serializing the rounds
@@ -860,30 +894,34 @@ class ServingEngine:
             if self._staged is not None:
                 self._staged_decode_block(R)
             else:
-                self._cache, self._state = self._decode_block(
-                    self.params, self._cache, self._state, R
-                )
+                with TraceAnnotation("serve.decode_block", rounds=R, lanes=lanes):
+                    self._cache, self._state = self._decode_block(
+                        self.params, self._cache, self._state, R
+                    )
         self.rounds += R
+        self.decode_blocks += 1
+        self.busy_lane_rounds += lanes * R
 
         groups = self._staged_groups
-        if groups is not None:
-            # per-group scalar sync: the decode state stays split
-            # between barriers, so read the per-lane flags group-wise
-            # instead of merging the whole state every block
-            gsize = sc.max_batch // len(groups)
-            # lint: disable=RPL002 -- the designed block-boundary sync: per-slot flags after R fused rounds
-            active = np.concatenate(
-                [np.asarray(gr["active"]) for gr in groups]
-            )
-            # lint: disable=RPL002 -- designed block-boundary sync (see above)
-            out_len = np.concatenate(
-                [np.asarray(gr["out_len"]) for gr in groups]
-            )
-        else:
-            # lint: disable=RPL002 -- the designed block-boundary sync: per-slot flags after R fused rounds
-            active = np.asarray(self._state["active"])
-            # lint: disable=RPL002 -- designed block-boundary sync (see above)
-            out_len = np.asarray(self._state["out_len"])
+        with TraceAnnotation("serve.block_sync"):
+            if groups is not None:
+                # per-group scalar sync: the decode state stays split
+                # between barriers, so read the per-lane flags group-wise
+                # instead of merging the whole state every block
+                gsize = sc.max_batch // len(groups)
+                # lint: disable=RPL002 -- the designed block-boundary sync: per-slot flags after R fused rounds
+                active = np.concatenate(
+                    [np.asarray(gr["active"]) for gr in groups]
+                )
+                # lint: disable=RPL002 -- designed block-boundary sync (see above)
+                out_len = np.concatenate(
+                    [np.asarray(gr["out_len"]) for gr in groups]
+                )
+            else:
+                # lint: disable=RPL002 -- the designed block-boundary sync: per-slot flags after R fused rounds
+                active = np.asarray(self._state["active"])
+                # lint: disable=RPL002 -- designed block-boundary sync (see above)
+                out_len = np.asarray(self._state["out_len"])
         now = time.perf_counter()
         for i, req in enumerate(self._slots):
             if req is None:
@@ -893,13 +931,14 @@ class ServingEngine:
             if not active[i]:
                 # lint: disable=RPL002 -- out_len already synced to host numpy above
                 n = int(out_len[i])
-                if groups is not None:
-                    gi, row = divmod(i, gsize)
-                    buf = groups[gi]["out_buf"][row, :n]
-                else:
-                    buf = self._state["out_buf"][i, :n]
-                # lint: disable=RPL002 -- designed drain of a finished request's tokens at the block boundary
-                req.out_tokens = [int(t) for t in np.asarray(buf)]
+                with TraceAnnotation("serve.drain", tokens=n):
+                    if groups is not None:
+                        gi, row = divmod(i, gsize)
+                        buf = groups[gi]["out_buf"][row, :n]
+                    else:
+                        buf = self._state["out_buf"][i, :n]
+                    # lint: disable=RPL002 -- designed drain of a finished request's tokens at the block boundary
+                    req.out_tokens = [int(t) for t in np.asarray(buf)]
                 req.done_at = now
                 self.completed.append(req)
                 self._slots[i] = None
@@ -972,9 +1011,7 @@ class ServingEngine:
         logits, cache = self.api.prefill(self.cfg, self.params, batch)
         self._cache = scatter_cache(self._cache, cache, slot, len(prompt))
         tok = self._sample(np.asarray(logits, np.float32)[0])
-        self.prefill_bucket_s.setdefault(len(prompt), []).append(
-            time.perf_counter() - t0
-        )
+        self._time_prefill(len(prompt), time.perf_counter() - t0)
         req.out_tokens.append(tok)
         req.first_token_at = time.perf_counter()
         # a single-token budget (or an eos first token) completes at
@@ -986,6 +1023,14 @@ class ServingEngine:
         self._slots[slot] = req
         self._slot_pos[slot] = len(prompt)
         self._slot_remaining[slot] = req.max_new_tokens - 1
+
+    def _time_prefill(self, bucket: int, seconds: float) -> None:
+        self.prefill_calls[bucket] = self.prefill_calls.get(bucket, 0) + 1
+        self.prefill_seconds[bucket] = self.prefill_seconds.get(bucket, 0.0) + seconds
+
+    def prefill_s_by_bucket(self) -> Dict[int, float]:
+        """Mean wall-clock seconds of an admitted prefill call, per bucket."""
+        return {b: self.prefill_seconds[b] / n for b, n in sorted(self.prefill_calls.items())}
 
     def _sample(self, logits: np.ndarray) -> int:
         if self.serve_cfg.temperature <= 0:
@@ -1063,8 +1108,14 @@ class ServingEngine:
             out["retraces_after_warmup"] = float(
                 self.tracing.total() - self._warm_traces
             )
-        for b, times in sorted(self.prefill_bucket_s.items()):
-            out[f"prefill_s_bucket{b}"] = float(np.mean(times))
+        for b, s in self.prefill_s_by_bucket().items():
+            out[f"prefill_s_bucket{b}"] = s
+        out.update(
+            prefill_tokens=self.prefill_tokens,
+            prefill_tokens_computed=self.prefill_tokens_computed,
+            decode_blocks=self.decode_blocks,
+            busy_lane_rounds=self.busy_lane_rounds,
+        )
         if self.streaming_plan is not None:
             out.update(
                 {f"stream_{k}": v for k, v in self.streaming_plan.summary().items()}
