@@ -1,6 +1,6 @@
 """Model -> kernel dispatch for the fused decode path.
 
-The models layer (``transformer._layer_fn``, ``encdec._dec_layer_fn``,
+The models layer (``transformer.decode_stage``, ``encdec._dec_layer_fn``,
 ``hybrid._shared_block``) calls these wrappers instead of touching
 ``decode.py`` directly, so every decode entry point -- the fused
 ``_decode_block`` scan, the per-stage loops, and the coalesced staged path

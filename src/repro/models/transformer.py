@@ -135,79 +135,38 @@ def layer_windows(cfg: ModelConfig) -> jax.Array:
 # ------------------------------------------------------------- forward ----
 
 
-def _layer_fn(
-    cfg: ModelConfig,
-    x: jax.Array,                 # (B, S, D)
-    lp: dict,
-    window: jax.Array,            # () int32
-    positions: jax.Array,         # (B, S)
-    cache_kv: Optional[Tuple[jax.Array, jax.Array]],   # (B, Smax, KV, hd) x2
-    decode_pos: Optional[jax.Array],                   # () or (B,) int32
-    return_kv: bool,
+def _qkv(
+    cfg: ModelConfig, x: jax.Array, lp: dict, positions: jax.Array,
+    use_kernels: bool,
 ):
-    dt = x.dtype
-    # fused decode kernels (kernels/decode.py) take over the single-token
-    # hot path when cfg.decode_kernels is set; cache write stays XLA.
-    use_kernels = kdispatch.attention_active(cfg, x) and cache_kv is not None
+    """Attention-norm and QKV projection (RoPE applied) -> q, k, v."""
     h = apply_norm(cfg, x, lp.get("attn_norm"))
     if use_kernels:
-        q, k, v = kdispatch.decode_qkv(
+        return kdispatch.decode_qkv(
             cfg, lp["attn"], h, positions, rope=cfg.pos_embed == "rope"
         )
-    else:
-        q, k, v = attn.project_qkv(cfg, lp["attn"], h)
-        if cfg.pos_embed == "rope":
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+    q, k, v = attn.project_qkv(cfg, lp["attn"], h)
+    if cfg.pos_embed == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    return q, k, v
 
-    new_cache = None
-    kv_positions = None
-    if cache_kv is not None:
-        cache_len = cache_kv[0].shape[1]
-        # ring buffer (pure-SWA): write round-robin; slot s holds absolute
-        # position pos - ((pos - s) mod L); never-written slots come out
-        # negative and are masked in attention.
-        ring = bool(cfg.kv_ring and cfg.window and not cfg.global_every)
-        # decode_pos may be () (all lanes aligned) or (B,) (staggered
-        # batched decode: each lane writes its own cache position)
-        per_lane = jnp.ndim(decode_pos) > 0
-        write_pos = decode_pos % cache_len if ring else decode_pos
-        if ring:
-            slots = jnp.arange(cache_len, dtype=jnp.int32)
-            if per_lane:
-                kv_positions = decode_pos[:, None] - (
-                    (decode_pos[:, None] - slots[None, :]) % cache_len
-                )
-            else:
-                kv_positions = decode_pos - ((decode_pos - slots) % cache_len)
 
-        def cwrite(buf, new):
-            new = new.astype(buf.dtype)
-            if per_lane:
-                # one-token decode: scatter each lane's row at its own pos
-                return buf.at[jnp.arange(buf.shape[0]), write_pos].set(new[:, 0])
-            start = (0, write_pos) + (0,) * (buf.ndim - 2)
-            return jax.lax.dynamic_update_slice(buf, new, start)
-
-        if cfg.kv_quant:
-            ck, cv, ke, ve = cache_kv
-            kq, ke_new = kv_quantize(k)
-            vq, ve_new = kv_quantize(v)
-            ck, cv = cwrite(ck, kq), cwrite(cv, vq)
-            ke, ve = cwrite(ke, ke_new), cwrite(ve, ve_new)
-            new_cache = (ck, cv, ke, ve)
-            k_att = kv_dequantize(ck, ke, dt)
-            v_att = kv_dequantize(cv, ve, dt)
-        else:
-            ck, cv = cache_kv
-            ck, cv = cwrite(ck, k), cwrite(cv, v)
-            new_cache = (ck, cv)
-            k_att, v_att = ck, cv
-        valid = decode_pos + x.shape[1]
-    else:
-        k_att, v_att = k, v
-        valid = None
-
+def _attend(
+    cfg: ModelConfig,
+    x: jax.Array,
+    lp: dict,
+    q: jax.Array,
+    k_att: jax.Array,             # (B, Sk, KV, hd)
+    v_att: jax.Array,
+    window: jax.Array,
+    positions: jax.Array,
+    valid: Optional[jax.Array],
+    kv_positions: Optional[jax.Array],
+    use_kernels: bool,
+) -> jax.Array:
+    """Residual attention block over k_att / v_att -> x."""
+    dt = x.dtype
     if use_kernels:
         x = x + kdispatch.decode_attention(
             cfg, lp["attn"], q, k_att.astype(dt), v_att.astype(dt),
@@ -227,13 +186,11 @@ def _layer_fn(
             chunk=cfg.attn_chunk,
         )
         x = x + attn.project_out(cfg, lp["attn"], ctx)
-    x = logical_constraint(x, "batch", "seq", "d_model")
+    return logical_constraint(x, "batch", "seq", "d_model")
 
-    if return_kv and cfg.kv_quant:
-        kq, ke_out = kv_quantize(k)
-        vq, ve_out = kv_quantize(v)
-        kv_quant_out = (kq, vq, ke_out, ve_out)
 
+def _mlp_block(cfg: ModelConfig, x: jax.Array, lp: dict) -> Tuple[jax.Array, jax.Array]:
+    """Residual MLP / MoE block -> (x, moe aux loss)."""
     h2 = apply_norm(cfg, x, lp.get("mlp_norm"))
     aux = jnp.zeros((), jnp.float32)
     if cfg.is_moe:
@@ -243,14 +200,39 @@ def _layer_fn(
     else:
         y = mlp_mod.mlp_apply(cfg, lp["mlp"], h2)
     x = x + y
-    x = logical_constraint(x, "batch", "seq", "d_model")
+    return logical_constraint(x, "batch", "seq", "d_model"), aux
+
+
+def _layer_fn(
+    cfg: ModelConfig,
+    x: jax.Array,                 # (B, S, D)
+    lp: dict,
+    window: jax.Array,            # () int32
+    positions: jax.Array,         # (B, S)
+    return_kv: bool,
+):
+    """One full-sequence layer -> (x, aux, this layer's k/v or None).
+
+    The single-token decode runs the same three blocks in
+    :func:`decode_stage`, with the cache write between them."""
+    q, k, v = _qkv(cfg, x, lp, positions, use_kernels=False)
+    x = _attend(
+        cfg, x, lp, q, k, v, window, positions, None, None, use_kernels=False
+    )
+
+    if return_kv and cfg.kv_quant:
+        kq, ke_out = kv_quantize(k)
+        vq, ve_out = kv_quantize(v)
+        kv_quant_out = (kq, vq, ke_out, ve_out)
+
+    x, aux = _mlp_block(cfg, x, lp)
     if not return_kv:
         kv_out = None
     elif cfg.kv_quant:
         kv_out = kv_quant_out
     else:
         kv_out = (k, v)
-    return x, aux, new_cache, kv_out
+    return x, aux, kv_out
 
 
 def _embed(cfg, params, tokens, patch_embeds, positions):
@@ -284,9 +266,7 @@ def forward_hidden(
     def body(carry, xs):
         x, aux_sum = carry
         lp, win = xs
-        x, aux, _, kv = _layer_fn(
-            cfg, x, lp, win, positions, None, None, return_kv=return_cache
-        )
+        x, aux, kv = _layer_fn(cfg, x, lp, win, positions, return_kv=return_cache)
         return (x, aux_sum + aux), kv
 
     if cfg.remat == "layer":
@@ -468,23 +448,81 @@ def decode_stage(
 
     ``stage_params``/``stage_cache`` come from :func:`slice_params` /
     :func:`slice_cache`; an empty slice is the identity (the hidden state
-    passes through untouched)."""
+    passes through untouched).
+
+    The stacked cache is the layer loop's carry: layer ``l`` writes its new
+    K/V row per lane at ``[l, lane, write_pos]`` and attends over layer
+    ``l`` of the updated carry, so no layer slice is re-stacked and the
+    round loop around this step updates the cache buffers in place."""
     if stage_params["layers"] and jax.tree.leaves(stage_params["layers"])[0].shape[0] == 0:
         return hidden, stage_cache
-    pos, positions = _decode_positions(pos, hidden.shape[0])
+    b = hidden.shape[0]
+    pos, positions = _decode_positions(pos, b)
+    # fused decode kernels (kernels/decode.py) take over QKV and attention
+    # when cfg.decode_kernels is set; the cache write stays XLA
+    use_kernels = kdispatch.attention_active(cfg, hidden)
+    dt = hidden.dtype
+    cache_len = stage_cache[0].shape[2]
+    # decode_pos may be () (all lanes aligned) or (B,) (staggered batched
+    # decode: each lane writes its own cache position)
+    per_lane = pos.ndim > 0
+    # ring buffer (pure-SWA): write round-robin; slot s holds absolute
+    # position pos - ((pos - s) mod L); never-written slots come out
+    # negative and are masked in attention.
+    ring = bool(cfg.kv_ring and cfg.window and not cfg.global_every)
+    write_pos = pos % cache_len if ring else pos
+    kv_positions = None
+    if ring:
+        slots = jnp.arange(cache_len, dtype=jnp.int32)
+        if per_lane:
+            kv_positions = pos[:, None] - (
+                (pos[:, None] - slots[None, :]) % cache_len
+            )
+        else:
+            kv_positions = pos - ((pos - slots) % cache_len)
+    valid = pos + 1
+    lanes = jnp.arange(b)
 
-    def body(x, xs):
-        lp, win = xs[0], xs[1]
-        x, _, new_cache, _ = _layer_fn(
-            cfg, x, lp, win, positions, tuple(xs[2:]), pos, return_kv=False
+    def cwrite(buf, layer, new):
+        # buf (L, B, S, ...), new (B, 1, ...): one row per lane of one layer
+        new = new.astype(buf.dtype)
+        if per_lane:
+            return buf.at[layer, lanes, write_pos].set(new[:, 0])
+        start = (layer, 0, write_pos) + (0,) * (buf.ndim - 3)
+        return jax.lax.dynamic_update_slice(buf, new[None], start)
+
+    def body(carry, xs):
+        x, cache, layer = carry
+        lp, win = xs
+        q, k, v = _qkv(cfg, x, lp, positions, use_kernels)
+        if cfg.kv_quant:
+            kq, ke_new = kv_quantize(k)
+            vq, ve_new = kv_quantize(v)
+            new_rows = (kq, vq, ke_new, ve_new)
+        else:
+            new_rows = (k, v)
+        cache = tuple(cwrite(c, layer, n) for c, n in zip(cache, new_rows))
+        view = tuple(
+            jax.lax.dynamic_index_in_dim(c, layer, keepdims=False) for c in cache
         )
-        return x, new_cache
+        if cfg.kv_quant:
+            ck, cv, ke, ve = view
+            k_att, v_att = kv_dequantize(ck, ke, dt), kv_dequantize(cv, ve, dt)
+        else:
+            k_att, v_att = view
+        x = _attend(
+            cfg, x, lp, q, k_att, v_att, win, positions, valid, kv_positions,
+            use_kernels,
+        )
+        x, _ = _mlp_block(cfg, x, lp)
+        return (x, cache, layer + 1), None
 
-    x, new_cache = jax.lax.scan(
-        body, hidden,
-        (stage_params["layers"], stage_params["windows"]) + tuple(stage_cache),
+    (x, cache, _), _ = jax.lax.scan(
+        body,
+        (hidden, tuple(stage_cache), jnp.zeros((), jnp.int32)),
+        (stage_params["layers"], stage_params["windows"]),
     )
-    return x, tuple(new_cache)
+    return x, cache
 
 
 def decode_unembed(cfg: ModelConfig, params: dict, hidden: jax.Array) -> jax.Array:
