@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import argparse
 import copy
+import dataclasses
 import json
 import os
 import sys
@@ -57,7 +58,7 @@ def main(argv=None) -> int:
     if args.mode == "sweep":
         runs = []
         for rate in [float(x) for x in args.rates.split(",")]:
-            cell = copy.deepcopy(base)
+            cell = dataclasses.replace(base, traffic=copy.deepcopy(base.traffic))
             cell.traffic["arrivals"]["rate_per_s"] = rate
             cell.traffic["window_opens"] = "after_lead"
             runs.append(({"rate_per_s": rate}, cell, args.seed))

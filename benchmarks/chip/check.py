@@ -2,7 +2,8 @@
 
 Once the window has closed and the program's state is freed, a sample
 drawn from the seed of the requests finished in the window (the longest
-of them always among it) is run through the plain float32 reference:
+of them always among it) is run through the plain float32 reference of
+the cell's architecture module (``archs/<name>.py``, ``logit_gaps``):
 each prompt with the tokens the engine served.  At every served token
 the reference's best logit less its logit of that token is the token's
 gap; a greedy engine that computes what the configuration states serves
@@ -26,8 +27,6 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
-from benchmarks.chip import work
-from benchmarks.chip.reference.model import logit_gaps
 from benchmarks.chip.weights import make_weights
 
 
@@ -43,17 +42,18 @@ def sample(records, w0: float, w1: float, seed: int, k: int) -> List[Tuple[np.nd
     return [(done[i].prompt, np.asarray(done[i].req.out_tokens, np.int32)) for i in picked]
 
 
-def compare(cell, m: work.Dims, seed: int, requests, info: Dict, control: bool = False) -> Dict:
-    """``correct``, ``failed`` and the numbers compared with their limits.
+def compare(cell, m, seed: int, requests, info: Dict, control: bool = False) -> Dict:
+    """``correct``, ``failed`` and the numbers compared with their limits;
+    ``m`` is the architecture's ``dims`` of the cell's configuration.
     With ``control`` the float8 control's gaps are judged in place of the
     program's, and the program's widest gap is returned beside them as
     ``program_logit_gap_max``."""
     limit = float(cell.config["check"]["logit_gap_limit"])
     t0 = time.perf_counter()
-    weights = make_weights(m, seed)
+    weights = make_weights(cell.arch.layout(m), seed)
     widest, program_widest, failed, tokens = 0.0, 0.0, 0, 0
     for prompt, served in requests:
-        g, cg = logit_gaps(m, cell.config, weights, prompt, served, control=control)
+        g, cg = cell.arch.logit_gaps(m, cell.config, weights, prompt, served, control=control)
         program_widest = max(program_widest, float(g.max()))
         judged = float((cg if control else g).max())
         widest = max(widest, judged)

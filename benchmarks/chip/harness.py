@@ -5,11 +5,14 @@ and ``ServingEngine.step``, on an engine built as ``repro.launch.serve``
 builds one, under an open-loop schedule from the cell's traffic file.
 Every request's due time is kept here: the engine's ``submitted_at`` is
 when ``submit`` was called, which under a synchronous ``step`` can be
-later than when the request was due.
+later than when the request was due.  The configuration's architecture
+module (``Cell.arch``, from ``archs/``) gives the shapes, the program's
+``ModelConfig`` and the layout of the seeded weights.
 
 After the window the run reads the device's peak memory, frees the
 program's state, and compares a seeded sample of the requests finished
-in the window with the plain float32 reference (``check.py``).
+in the window with the architecture's plain float32 reference
+(``check.py``).
 """
 from __future__ import annotations
 
@@ -22,47 +25,18 @@ import sys
 import tempfile
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 import jax
 
-from benchmarks.chip import check, device, trace, work
+from benchmarks.chip import check, device, trace
 from benchmarks.chip.cells import Cell, readers
 from benchmarks.chip.weights import check_layout, make_weights
 
 # the engine's callables whose launches the traced run tells apart
 DISPATCHED = {"_admit_block": "admit", "_decode_block": "decode"}
-
-# published config.json keys -> the program's ModelConfig fields
-HF_TO_PROGRAM = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab",
-    "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings",
-}
-
-
-def model_config(conf: Dict):
-    """The program's ModelConfig for a configuration file: the registered
-    architecture, with the file's published sizes (and its cuts) set."""
-    from repro.configs import get_config
-
-    base = get_config(conf["arch"])
-    changes = {
-        field: type(getattr(base, field))(conf[key])
-        for key, field in HF_TO_PROGRAM.items()
-        if key in conf and getattr(base, field) != conf[key]
-    }
-    if "head_dim" not in conf and {"d_model", "n_heads"} & changes.keys():
-        changes["head_dim"] = conf["hidden_size"] // conf["num_attention_heads"]
-    return dataclasses.replace(base, **changes)
-
 
 def prompt_buckets(traffic: Dict) -> tuple:
     """Power-of-two prompt buckets covering the traffic's prompt lengths."""
@@ -112,7 +86,7 @@ class Record:
 class ServeRun:
     """What a per-layer metric reader sees of one run."""
     cell: Cell
-    dims: work.Dims
+    dims: Any                        # the cell's architecture's dims(config)
     peaks: Optional[Dict]
     max_batch: int
     buckets: tuple
@@ -217,11 +191,11 @@ def serve(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
 
     dev = device.require_chips(cell.chips) if on_chip else device.describe(cell.chips)
     pk = device.peaks(dev["kind"]) if on_chip else None
-    m = work.dims(cell.config)
-    cfg = model_config(cell.config)
+    m = cell.arch.dims(cell.config)
+    cfg = cell.arch.program_config(cell.config)
     tr, eng_conf = cell.traffic, cell.traffic["engine"]
     api = model_api.get_api(cfg)
-    weights = make_weights(m, seed)
+    weights = make_weights(cell.arch.layout(m), seed)
     check_layout(
         jax.eval_shape(lambda: weights),
         jax.eval_shape(functools.partial(api.init_params, cfg), jax.random.PRNGKey(0)),

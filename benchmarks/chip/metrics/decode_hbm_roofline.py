@@ -1,8 +1,7 @@
 """Bytes the decode rounds need, at the HBM peak, over the decode-block
 device time: per round the weights in bfloat16 once, and the keys and
-values of every active lane's live positions (work.decode_bytes)."""
-
-from benchmarks.chip import work
+values of every active lane's live positions (the architecture's
+``decode_bytes``)."""
 
 
 def read(run):
@@ -10,7 +9,7 @@ def read(run):
     if prog is None or prog["decode"] <= 0:
         return None
     need = sum(
-        work.decode_bytes(run.dims, lanes)
+        run.cell.arch.decode_bytes(run.dims, lanes)
         for i in prog["steps"]
         for lanes in run.lane_positions(i)
     )

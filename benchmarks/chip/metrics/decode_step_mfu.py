@@ -1,8 +1,6 @@
-"""Model operations of the decode rounds (active lanes only,
-work.decode_flops) over the decode-block device time at the bf16 peak:
-the whole decode program's share of the chip's peak."""
-
-from benchmarks.chip import work
+"""Model operations of the decode rounds (active lanes only, the
+architecture's ``decode_flops``) over the decode-block device time at
+the bf16 peak: the whole decode program's share of the chip's peak."""
 
 
 def read(run):
@@ -10,7 +8,7 @@ def read(run):
     if prog is None or prog["decode"] <= 0:
         return None
     flops = sum(
-        work.decode_flops(run.dims, lanes)
+        run.cell.arch.decode_flops(run.dims, lanes)
         for i in prog["steps"]
         for lanes in run.lane_positions(i)
     )

@@ -1,7 +1,6 @@
-"""Operations the real prompt tokens need (work.prefill_flops, no
-padding), at the bf16 peak, over the admission programs' device time."""
-
-from benchmarks.chip import work
+"""Operations the real prompt tokens need (the architecture's
+``prefill_flops``, no padding), at the bf16 peak, over the admission
+programs' device time."""
 
 
 def read(run):
@@ -9,7 +8,7 @@ def read(run):
     if prog is None or prog["admit"] <= 0:
         return None
     flops = sum(
-        work.prefill_flops(run.dims, len(r.prompt))
+        run.cell.arch.prefill_flops(run.dims, len(r.prompt))
         for i in prog["steps"]
         for r in run.admissions(i)
     )

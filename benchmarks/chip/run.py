@@ -4,8 +4,9 @@
         --seconds <s> --trace <0|1>
 
 The cell is looked up by name in ``BENCHMARK.json`` at the root of the
-checkout; its configuration, traffic mix and per-layer metrics are files
-under ``benchmarks/chip/``.  The run refuses (exit code 3, no result) a
+checkout; its configuration, the configuration's architecture module,
+its traffic mix and its per-layer metrics are files under
+``benchmarks/chip/``.  The run refuses (exit code 3, no result) a
 host whose JAX finds no TPU or fewer chips than the cell asks for.  The
 last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics,

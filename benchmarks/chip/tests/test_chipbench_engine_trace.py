@@ -7,8 +7,8 @@ from unittest import mock
 import numpy as np
 import pytest
 
-from chipbench_helpers import BENCH, DATA, new_cell_root
-from benchmarks.chip import cells, engine_trace, harness, record_engine_trace, trace, work
+from chipbench_helpers import BENCH, DATA, launch_run, new_cell_root
+from benchmarks.chip import cells, engine_trace, harness, record_engine_trace, trace
 from benchmarks.chip.cells import load_module
 
 TWO_PROGRAMS = DATA / "two_programs.xplane.pb"
@@ -153,37 +153,14 @@ def test_engine_idle_lies_inside_the_window_idle(steps):
     assert any(n.startswith("serve.") for n in names)
 
 
-def _run(programs, names):
-    """The by-order fixture of test_chipbench_harness, its launches named
-    ``names[kind]``, at tiny-olmo's shapes on a v5e."""
-    import json
-
-    steps = [harness.Step(0.0, 1.0, 4, [("admit", 0.05), ("decode", 0.25)]),
-             harness.Step(1.0, 2.0, 2, [("admit", 1.05), ("decode", 1.45)]),
-             harness.Step(2.0, 3.0, 8, [("decode", 2.05)])]
-    req = lambda first, n: SimpleNamespace(first_token_at=first, done_at=None, out_tokens=[])
-    recs = [harness.Record(0, 0, 0, np.zeros(30, np.int32), 20, req(0.5, 20)),
-            harness.Record(1, 0, 0, np.zeros(31, np.int32), 20, req(0.6, 20)),
-            harness.Record(2, 0, 0, np.zeros(100, np.int32), 20, req(1.5, 20))]
-    L = lambda k, s, e: trace.Launch(names[k], int(s * 1e9), int(e * 1e9))
-    launches = [L("admit", -0.001, 0.2), L("decode", 0.3, 0.6), L("read", 0.6, 0.61),
-                L("admit", 1.1, 1.4), L("decode", 1.5, 1.7), L("decode", 2.1, 2.9)]
-    red = trace.Reduction(window_ns=(0, int(3e9)), busy_s={"d0": 2.0},
-                          launches={"d0": launches}, collective_s={}, top_ops=[], idle_gaps=[])
-    conf = json.loads((DATA / "tiny-olmo.json").read_text())
-    return harness.ServeRun(None, work.dims(conf), {"bf16_flop_per_s": 197e12, "hbm_byte_per_s": 819e9},
-                            4, (32, 64, 128), (0.0, 3.0), (0.0, 3.0), steps, recs, red,
-                            programs=frozenset(programs))
-
-
 @pytest.mark.parametrize("metric", LAUNCH_METRICS)
 def test_launch_metrics_read_the_same_under_the_program_names(metric):
     read = load_module(BENCH / "metrics" / f"{metric}.py").read
-    before = read(_run({"jit_traced"}, {"admit": "jit_traced(1)", "decode": "jit_traced(2)",
-                                        "read": "jit__getitem"}))
-    after = read(_run({"jit__admit_impl", "jit__decode_block_impl"},
-                      {"admit": "jit__admit_impl(1)", "decode": "jit__decode_block_impl(2)",
-                       "read": "jit_dynamic_slice"}))
+    before = read(launch_run({"jit_traced"}, {"admit": "jit_traced(1)", "decode": "jit_traced(2)",
+                                              "read": "jit__getitem"}))
+    after = read(launch_run({"jit__admit_impl", "jit__decode_block_impl"},
+                            {"admit": "jit__admit_impl(1)", "decode": "jit__decode_block_impl(2)",
+                             "read": "jit_dynamic_slice"}))
     assert before is not None and after == before
 
 

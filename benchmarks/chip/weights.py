@@ -3,8 +3,8 @@
 The benchmark makes the weights, not the program: the engine is handed
 this tree, and the plain reference makes the same tree again from the
 same seed once the program's state is freed.  The tree has the layout
-the program's transformer takes (stacked layers, ``(in, out)``
-matrices); :func:`check_layout` holds it to the program's own
+that the cell's architecture module gives (``archs/<name>.py``,
+``layout(m)``); :func:`check_layout` holds it to the program's own
 ``init_params`` shapes, so a layout change shows as an error and not as
 a wrong answer.
 """
@@ -14,8 +14,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from benchmarks.chip.work import Dims
 
 STD = 0.02
 
@@ -27,40 +25,9 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def _layout(m: Dims) -> Dict[Tuple[str, ...], Tuple[Tuple[int, ...], str]]:
-    """Leaf path -> (shape, kind); kind is 'normal', 'scale' or 'bias'."""
-    L, d, hd = m.layers, m.d, m.head_dim
-    q, kv = m.heads * hd, m.kv_heads * hd
-    out: Dict[Tuple[str, ...], Tuple[Tuple[int, ...], str]] = {
-        ("embed",): ((m.vocab, d), "normal"),
-        ("layers", "attn", "wq"): ((L, d, q), "normal"),
-        ("layers", "attn", "wk"): ((L, d, kv), "normal"),
-        ("layers", "attn", "wv"): ((L, d, kv), "normal"),
-        ("layers", "attn", "wo"): ((L, q, d), "normal"),
-        ("layers", "mlp", "w_up"): ((L, d, m.ff), "normal"),
-        ("layers", "mlp", "w_down"): ((L, m.ff, d), "normal"),
-    }
-    if not m.tied:
-        out[("unembed",)] = ((d, m.vocab), "normal")
-    if m.gated:
-        out[("layers", "mlp", "w_gate")] = ((L, d, m.ff), "normal")
-    if m.bias:
-        for name, n in (("bq", q), ("bk", kv), ("bv", kv), ("bo", d)):
-            out[("layers", "attn", name)] = ((L, n), "bias")
-        out[("layers", "mlp", "b_up")] = ((L, m.ff), "bias")
-        out[("layers", "mlp", "b_down")] = ((L, d), "bias")
-    if m.norm_affine:
-        for norm in ("attn_norm", "mlp_norm"):
-            out[("layers", norm, "scale")] = ((L, d), "scale")
-            out[("layers", norm, "bias")] = ((L, d), "bias")
-        out[("final_norm", "scale")] = ((d,), "scale")
-        out[("final_norm", "bias")] = ((d,), "bias")
-    return out
-
-
-def _make(m: Dims, key: jax.Array) -> dict:
+def _make(leaves: Tuple, key: jax.Array) -> dict:
     tree: dict = {}
-    for i, (path, (shape, kind)) in enumerate(sorted(_layout(m).items())):
+    for i, (path, (shape, kind)) in enumerate(leaves):
         x = STD * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
         if kind == "scale":
             x = 1.0 + x
@@ -71,9 +38,14 @@ def _make(m: Dims, key: jax.Array) -> dict:
     return tree
 
 
-def make_weights(m: Dims, seed: int) -> dict:
-    """The whole float32 weight tree of ``m`` for ``seed``, on the device."""
-    return jax.jit(_make, static_argnums=0)(m, seed_key(seed))
+def make_weights(layout: Dict[Tuple[str, ...], Tuple[Tuple[int, ...], str]],
+                 seed: int) -> dict:
+    """The whole float32 weight tree of ``layout`` (an architecture's
+    ``layout(m)``: leaf path -> (shape, kind), kind 'normal', 'scale' or
+    'bias') for ``seed``, on the device.  Leaf ``i`` in the sorted order
+    of paths is drawn from the seed's key folded with ``i``: normal with
+    deviation ``STD``, and scales at 1 plus that draw."""
+    return jax.jit(_make, static_argnums=0)(tuple(sorted(layout.items())), seed_key(seed))
 
 
 def check_layout(weights_shape: dict, program_shape: dict) -> None:
